@@ -15,7 +15,9 @@ constraints) and evaluates it at the config-zoo shapes
     (page tables modeled at their worst-case entry);
   * K105 — a page table too short to cover the declared context length;
   * K106 — GQA head counts that do not divide (``H % KV != 0``);
-  * K107 — a public kernel entry in ``ops.py`` with no lint spec at all.
+  * K107 — a public kernel entry in ``ops.py`` with no lint spec at all;
+  * K108 — a block shape Mosaic refuses: the last two dimensions of every
+    block must be multiples of (8, 128) or span the operand's dimension.
 
 The RNG half checks the determinism contract PR 5's closed loop relies
 on: per-(round, step, env) ``fold_in`` keying must be injective over its
@@ -117,20 +119,17 @@ def paged_invocation(shape_name: str, *, B: int, H: int, D: int, P: int,
     """Mirrors ``paged_attention_bhd``.  ``table_max`` models the largest
     page id a block table can hold (defaults to the pool's last page,
     P - 1 — the allocator's worst case)."""
-    G = max(H // KV, 1) if KV > 0 else 1
     tmax = (P - 1) if table_max is None else table_max
     return KernelInvocation(
         kernel="paged_attention", shape_name=shape_name,
-        grid=(B, KV, nb),
+        grid=(B, nb),
         operands=[
-            BlockMap("q", (B, KV, G, D), (1, 1, G, D),
-                     lambda b, kv, j: (b, kv, 0, 0)),
-            BlockMap("k_pages", (P, page, KV, D), (1, page, 1, D),
-                     lambda b, kv, j, t=tmax: (t, 0, kv, 0)),
-            BlockMap("v_pages", (P, page, KV, D), (1, page, 1, D),
-                     lambda b, kv, j, t=tmax: (t, 0, kv, 0)),
-            BlockMap("o", (B, KV, G, D), (1, 1, G, D),
-                     lambda b, kv, j: (b, kv, 0, 0)),
+            BlockMap("q", (B, H, D), (1, H, D), lambda b, j: (b, 0, 0)),
+            BlockMap("k_pages", (P, page * KV, D), (1, page * KV, D),
+                     lambda b, j, t=tmax: (t, 0, 0)),
+            BlockMap("v_pages", (P, page * KV, D), (1, page * KV, D),
+                     lambda b, j, t=tmax: (t, 0, 0)),
+            BlockMap("o", (B, H, D), (1, H, D), lambda b, j: (b, 0, 0)),
         ],
         constraints=[
             Divisibility("H % num_kv_heads", H, KV, code="K106"),
@@ -150,12 +149,16 @@ def ssd_invocation(shape_name: str, *, B: int, L: int, H: int, P: int,
         operands=[
             BlockMap("x", (B, H, nc, chunk, P), (1, 1, 1, chunk, P),
                      lambda b, h, ci: (b, h, ci, 0, 0)),
-            BlockMap("dt", (B, H, nc, chunk), (1, 1, 1, chunk),
-                     lambda b, h, ci: (b, h, ci, 0)),
+            BlockMap("dt", (B, H, nc, 1, chunk), (1, 1, 1, 1, chunk),
+                     lambda b, h, ci: (b, h, ci, 0, 0)),
+            BlockMap("a_cum", (B, H, nc, 1, chunk), (1, 1, 1, 1, chunk),
+                     lambda b, h, ci: (b, h, ci, 0, 0)),
             BlockMap("Bm", (B, nc, chunk, N), (1, 1, chunk, N),
                      lambda b, h, ci: (b, ci, 0, 0)),
             BlockMap("Cm", (B, nc, chunk, N), (1, 1, chunk, N),
                      lambda b, h, ci: (b, ci, 0, 0)),
+            BlockMap("D", (B, H, 1, 1), (1, 1, 1, 1),
+                     lambda b, h, ci: (b, h, 0, 0)),
             BlockMap("y", (B, H, nc, chunk, P), (1, 1, 1, chunk, P),
                      lambda b, h, ci: (b, h, ci, 0, 0)),
         ],
@@ -202,15 +205,18 @@ def ssm_update_invocation(shape_name: str, *, B: int, H: int, P: int,
         operands=[
             BlockMap("state", (B, H, P, N), (1, 1, P, N),
                      lambda b, h: (b, h, 0, 0)),
-            BlockMap("x", (B, H, P), (1, 1, P),
-                     lambda b, h: (b, h, 0)),
-            BlockMap("dt", (B, H), (1, 1), lambda b, h: (b, h)),
-            BlockMap("A", (B, H), (1, 1), lambda b, h: (b, h)),
-            BlockMap("Bm", (B, N), (1, N), lambda b, h: (b, 0)),
-            BlockMap("Cm", (B, N), (1, N), lambda b, h: (b, 0)),
-            BlockMap("D", (B, H), (1, 1), lambda b, h: (b, h)),
-            BlockMap("y", (B, H, P), (1, 1, P),
-                     lambda b, h: (b, h, 0)),
+            BlockMap("x", (B, H, P, 1), (1, 1, P, 1),
+                     lambda b, h: (b, h, 0, 0)),
+            BlockMap("dt", (B, H, 1, 1), (1, 1, 1, 1),
+                     lambda b, h: (b, h, 0, 0)),
+            BlockMap("A", (B, H, 1, 1), (1, 1, 1, 1),
+                     lambda b, h: (b, h, 0, 0)),
+            BlockMap("Bm", (B, 1, N), (1, 1, N), lambda b, h: (b, 0, 0)),
+            BlockMap("Cm", (B, N, 1), (1, N, 1), lambda b, h: (b, 0, 0)),
+            BlockMap("D", (B, H, 1, 1), (1, 1, 1, 1),
+                     lambda b, h: (b, h, 0, 0)),
+            BlockMap("y", (B, H, P, 1), (1, 1, P, 1),
+                     lambda b, h: (b, h, 0, 0)),
             BlockMap("new_state", (B, H, P, N), (1, 1, P, N),
                      lambda b, h: (b, h, 0, 0)),
         ])
@@ -240,16 +246,18 @@ def moe_decode_invocation(shape_name: str, *, T: int, E: int, d: int,
 
 def sampling_invocation(shape_name: str, *, B: int, V: int
                         ) -> KernelInvocation:
-    """Mirrors ``fused_sample`` -> ``fused_sample_bv``: grid (B,), one
-    (1, V) logits/gumbel row per program, (1, 1) token/logprob outs."""
+    """Mirrors ``fused_sample`` -> ``fused_sample_bv``: the batch pads to
+    a multiple of 8 rows, one (8, V) logits/gumbel block per program,
+    (8, 1) token/logprob outs."""
+    Bp = -(-B // 8) * 8
     return KernelInvocation(
         kernel="fused_sample", shape_name=shape_name,
-        grid=(B,),
+        grid=(Bp // 8,),
         operands=[
-            BlockMap("logits", (B, V), (1, V), lambda b: (b, 0)),
-            BlockMap("gumbel", (B, V), (1, V), lambda b: (b, 0)),
-            BlockMap("token", (B, 1), (1, 1), lambda b: (b, 0)),
-            BlockMap("lp", (B, 1), (1, 1), lambda b: (b, 0)),
+            BlockMap("logits", (Bp, V), (8, V), lambda b: (b, 0)),
+            BlockMap("gumbel", (Bp, V), (8, V), lambda b: (b, 0)),
+            BlockMap("token", (Bp, 1), (8, 1), lambda b: (b, 0)),
+            BlockMap("lp", (Bp, 1), (8, 1), lambda b: (b, 0)),
         ])
 
 
@@ -290,6 +298,7 @@ def check_invocation(inv: KernelInvocation) -> List[Finding]:
                     f"({blk} > {dim})",
                     "clamp the block to min(block, dim) like the "
                     "wrappers do"))
+    out.extend(_check_tiling(inv))
     if not any(f.code in ("K101", "K103") for f in out):
         out.extend(_check_index_maps(inv))
     if inv.coverage is not None:
@@ -301,6 +310,31 @@ def check_invocation(inv: KernelInvocation) -> List[Finding]:
                 f"position {covered} address past the block table",
                 "size the table at ceil(max_seq_len / page_size) pages "
                 "(PagedEngine.max_blocks does this)"))
+    return out
+
+
+# Mosaic tiles the last two dimensions of a VMEM block by (sublanes,
+# lanes) = (8, 128) for 32-bit data, unless the block spans the dimension.
+_TILE = (8, 128)
+
+
+def _check_tiling(inv: KernelInvocation) -> List[Finding]:
+    """K108 — the block-shape rule the TPU compiler enforces, which the
+    Pallas interpreter does not."""
+    out: List[Finding] = []
+    for op in inv.operands:
+        dims = list(zip(op.block_shape, op.operand_shape))[-2:]
+        for (blk, dim), tile in zip(dims, _TILE[-len(dims):]):
+            if blk % tile and blk < dim:  # blk > dim is K103
+                out.append(_f(
+                    "K108", "error", f"{inv.subject}:{op.name}",
+                    f"block shape {op.block_shape} over operand shape "
+                    f"{op.operand_shape}: Mosaic needs the last two block "
+                    f"dims divisible by {_TILE} or equal to the operand's",
+                    "block whole rows (e.g. 8 at a time), span the "
+                    "dimension, or insert a unit axis so the block spans "
+                    "it"))
+                break
     return out
 
 

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 
 def _gmm_kernel(buf_ref, w_ref, o_ref, acc_scr, *, num_d_blocks: int):
@@ -55,7 +54,7 @@ def moe_decode_gmm(
     up_w: jax.Array,  # (E, d, f)
     down_w: jax.Array,  # (E, f, d)
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Expert-parallel decode FFN: token→expert gather into a drop-free
     per-expert buffer, three grouped GEMMs, weighted scatter-add back.
@@ -97,7 +96,7 @@ def grouped_matmul(
     block_c: int = 128,
     block_d: int = 512,
     block_f: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     E, C, D = buf.shape
     F = w.shape[-1]
@@ -121,7 +120,7 @@ def grouped_matmul(
                                lambda e, ci, fi, di: (e, ci, fi)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), buf.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

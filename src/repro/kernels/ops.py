@@ -1,13 +1,12 @@
 """Jit-friendly wrappers dispatching model layouts onto the Pallas kernels.
 
-On this CPU container kernels always run with ``interpret=True`` (the
-Pallas interpreter executes the kernel body on CPU for correctness); on a
-real TPU backend set ``repro.kernels.ops.INTERPRET = False`` (or rely on
-the automatic backend check) to compile them with Mosaic.
+This module is the one place that decides how a kernel runs: compiled by
+Mosaic on a TPU backend, and under the Pallas interpreter (which executes
+the kernel body on the host, for correctness) on any other backend.  The
+kernel entries themselves take ``interpret`` with no default, so a caller
+that bypasses this module has to choose.
 """
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -19,13 +18,8 @@ from repro.kernels import sampling as _samp
 from repro.kernels import ssd_scan as _ssd
 from repro.kernels import ssm_update as _ssu
 
-# interpret=True whenever we're not actually on TPU
-INTERPRET: Optional[bool] = None
-
 
 def _interpret() -> bool:
-    if INTERPRET is not None:
-        return INTERPRET
     return jax.default_backend() != "tpu"
 
 

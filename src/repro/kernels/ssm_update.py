@@ -10,15 +10,21 @@ This is the serve tier's per-step hot op for SSM/hybrid cache layouts —
 the state-cache analogue of paged attention: constant-size work per
 request per token, no sequence dimension.
 
+Mosaic tiles the last two dimensions of a block by (8, 128) unless the
+block spans them whole, so every per-(b, h) operand travels with a unit
+axis that makes its block span the tiled dimensions: vectors as (P, 1)
+columns or (1, N) rows, scalars as (1, 1) blocks.  The wrapper's
+reshapes are free.
+
 Layouts:
   state: (B, H, P, N)  block (1, 1, P, N)   f32 running SSD state
-  x:     (B, H, P)     block (1, 1, P)      post-conv head inputs
-  dt:    (B, H)        block (1, 1)         post-softplus step size
-  A:     (B, H)        block (1, 1)         negative decay rate
-  Bm:    (B, N)        block (1, N)         input projection (per batch)
-  Cm:    (B, N)        block (1, N)         readout projection
-  D:     (B, H)        block (1, 1)         skip gain
-  y:     (B, H, P)     block (1, 1, P)
+  x:     (B, H, P, 1)  block (1, 1, P, 1)   post-conv head inputs
+  dt:    (B, H, 1, 1)  block (1, 1, 1, 1)   post-softplus step size
+  A:     (B, H, 1, 1)  block (1, 1, 1, 1)   negative decay rate
+  Bm:    (B, 1, N)     block (1, 1, N)      input projection (per batch)
+  Cm:    (B, N, 1)     block (1, N, 1)      readout projection
+  D:     (B, H, 1, 1)  block (1, 1, 1, 1)   skip gain
+  y:     (B, H, P, 1)  block (1, 1, P, 1)
   state':(B, H, P, N)  block (1, 1, P, N)
 """
 from __future__ import annotations
@@ -26,25 +32,24 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssm_update_kernel(state_ref, x_ref, dt_ref, a_ref, b_ref, c_ref,
                        d_ref, y_ref, new_state_ref):
     state = state_ref[0, 0].astype(jnp.float32)  # (P, N)
-    x = x_ref[0, 0].astype(jnp.float32)  # (P,)
-    dt = dt_ref[0, 0].astype(jnp.float32)  # scalar
-    A = a_ref[0, 0].astype(jnp.float32)  # scalar
-    Bm = b_ref[0].astype(jnp.float32)  # (N,)
-    Cm = c_ref[0].astype(jnp.float32)  # (N,)
-    Dh = d_ref[0, 0].astype(jnp.float32)  # scalar
+    x = x_ref[0, 0].astype(jnp.float32)  # (P, 1)
+    dt = dt_ref[0, 0].astype(jnp.float32)  # (1, 1)
+    A = a_ref[0, 0].astype(jnp.float32)  # (1, 1)
+    Bm = b_ref[0].astype(jnp.float32)  # (1, N)
+    Cm = c_ref[0].astype(jnp.float32)  # (N, 1)
+    Dh = d_ref[0, 0].astype(jnp.float32)  # (1, 1)
 
     decay = jnp.exp(dt * A)
-    new_state = state * decay + (dt * x)[:, None] * Bm[None, :]  # (P, N)
-    y = jnp.dot(new_state, Cm, preferred_element_type=jnp.float32)  # (P,)
-    y_ref[0, 0, :] = (y + Dh * x).astype(y_ref.dtype)
-    new_state_ref[0, 0, :, :] = new_state.astype(new_state_ref.dtype)
+    new_state = state * decay + (dt * x) * Bm  # (P, N)
+    y = jnp.dot(new_state, Cm, preferred_element_type=jnp.float32)  # (P, 1)
+    y_ref[0, 0] = (y + Dh * x).astype(y_ref.dtype)
+    new_state_ref[0, 0] = new_state.astype(new_state_ref.dtype)
 
 
 def ssm_state_update_bh(
@@ -56,32 +61,36 @@ def ssm_state_update_bh(
     Cm: jax.Array,  # (B, N)
     D: jax.Array,  # (B, H)
     *,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Returns (y (B, H, P) f32, new_state (B, H, P, N) f32)."""
     B, H, P, N = state.shape
-    return pl.pallas_call(
+    scalar = pl.BlockSpec((1, 1, 1, 1), lambda b, h: (b, h, 0, 0))
+    y, new_state = pl.pallas_call(
         _ssm_update_kernel,
         grid=(B, H),
         in_specs=[
             pl.BlockSpec((1, 1, P, N), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, P), lambda b, h: (b, h, 0)),
-            pl.BlockSpec((1, 1), lambda b, h: (b, h)),
-            pl.BlockSpec((1, 1), lambda b, h: (b, h)),
-            pl.BlockSpec((1, N), lambda b, h: (b, 0)),
-            pl.BlockSpec((1, N), lambda b, h: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, h: (b, h)),
+            pl.BlockSpec((1, 1, P, 1), lambda b, h: (b, h, 0, 0)),
+            scalar,
+            scalar,
+            pl.BlockSpec((1, 1, N), lambda b, h: (b, 0, 0)),
+            pl.BlockSpec((1, N, 1), lambda b, h: (b, 0, 0)),
+            scalar,
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, P), lambda b, h: (b, h, 0)),
+            pl.BlockSpec((1, 1, P, 1), lambda b, h: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, P), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, P, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
-    )(state, x, dt, A, Bm, Cm, D)
+    )(state, x.reshape(B, H, P, 1), dt.reshape(B, H, 1, 1),
+      A.reshape(B, H, 1, 1), Bm.reshape(B, 1, N), Cm.reshape(B, N, 1),
+      D.reshape(B, H, 1, 1))
+    return y.reshape(B, H, P), new_state

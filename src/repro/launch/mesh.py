@@ -10,21 +10,11 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax.sharding has no AxisType
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    """``jax.make_mesh`` across jax versions: pass ``axis_types`` only when
-    the pinned jax supports it; otherwise plain axis handling."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -34,7 +24,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
 
 
 def make_local_mesh(model: int = 1, data: int = 1) -> Mesh:
-    """Small mesh over however many local devices exist (tests)."""
+    """(data, model) mesh over the first ``data * model`` devices."""
     n = len(jax.devices())
     assert model * data <= n, (model, data, n)
     return _make_mesh((data, model), ("data", "model"))

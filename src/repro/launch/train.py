@@ -1,14 +1,18 @@
 """Production training launcher: mesh + sharded state + train loop.
 
-On a real TPU pod this is the per-host entry point (`jax.distributed`
-initializes from the TPU environment); on CPU it runs the same code path
-on a 1×1 mesh with a reduced config (--smoke), so the launcher itself is
-exercised by CI.
+The mesh spans every device JAX sees, (data=N, model=1): weights and
+optimizer state shard FSDP-style over the data axis.  In a multi-host
+job (``REPRO_COORD_ADDR``, ``REPRO_NUM_PROCESSES``, ``REPRO_PROCESS_ID``
+set; see ``launch.cluster.maybe_init_jax_distributed``) this process
+joins it through ``jax.distributed`` before anything touches the
+backend.  ``--smoke`` runs a reduced-width config, so the launcher itself
+is exercised on CPU.
 
 Usage:
   python -m repro.launch.train --arch yi-9b --smoke --steps 10
-  python -m repro.launch.train --arch mistral-large-123b \
-      --seq 4096 --batch 256 --multi-pod        # on a 512-chip pod slice
+  REPRO_COORD_ADDR=$HOST:1234 REPRO_NUM_PROCESSES=64 REPRO_PROCESS_ID=$I \
+      python -m repro.launch.train --arch mistral-large-123b --seq 4096 \
+      --batch 256
 """
 from __future__ import annotations
 
@@ -21,12 +25,14 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
-from repro.launch.mesh import make_local_mesh, make_production_mesh
+from repro.launch.cluster import maybe_init_jax_distributed
+from repro.launch.mesh import make_local_mesh
 from repro.models import init_model
 from repro.train import TrainHParams, init_adamw, lm_loss, make_train_step
 from repro.train.checkpoint import save_checkpoint
 from repro.train.optimizer import AdamWConfig
 from repro.train.sharding_rules import array_batch_specs, param_specs
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.logging import log
 from repro.utils.sharding import set_active_mesh
 
@@ -40,19 +46,16 @@ def main(argv=None) -> int:
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--n-micro", type=int, default=1)
     ap.add_argument("--smoke", action="store_true",
-                    help="reduced config + local mesh (CPU)")
-    ap.add_argument("--multi-pod", action="store_true")
+                    help="reduced-width config (CPU)")
     ap.add_argument("--checkpoint", default="")
     args = ap.parse_args(argv)
 
+    maybe_init_jax_distributed()  # must precede every backend query
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
-        mesh = make_local_mesh()
-    else:
-        if jax.process_count() > 1 or "tpu" in jax.default_backend():
-            jax.distributed.initialize()
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+    mesh = make_local_mesh(data=jax.device_count())
     set_active_mesh(mesh)
     log("launch", f"arch={cfg.name} mesh={dict(mesh.shape)} "
         f"params≈{cfg.param_count() / 1e9:.2f}B")

@@ -15,14 +15,16 @@ def grpo_advantages(rewards: np.ndarray, group_size: int,
     """Group-relative advantages (GRPO): responses to the same query form a
     group; advantage = (r - mean_group) / std_group, broadcast per token by
     the caller.  rewards: (B,) with B = n_queries * group_size, grouped
-    consecutively."""
+    consecutively.  The group statistics are taken in float64, so a group
+    whose rewards nearly tie still has a mean advantage of ~0; the
+    result is float32."""
     B = rewards.shape[0]
     assert B % group_size == 0, (B, group_size)
-    g = rewards.reshape(B // group_size, group_size)
+    g = rewards.reshape(B // group_size, group_size).astype(np.float64)
     mean = g.mean(axis=1, keepdims=True)
     std = g.std(axis=1, keepdims=True)
     adv = (g - mean) / (std + eps)
-    return adv.reshape(B)
+    return adv.reshape(B).astype(np.float32)
 
 
 def reinforce_pp_advantages(rewards: np.ndarray,

@@ -130,16 +130,19 @@ class GRPORunner(WorkflowRunner):
     # ------------------------------------------------------------------
     def build_workers(self) -> Dict[str, Any]:
         cfg, rl = self.model_cfg, self.rl
+        # construction-time slices (the plan rebinds them), capped at the
+        # live devices so a one- or two-device cluster can host them
+        n = len(self.cluster.available_devices())
         self.actor = ActorWorker(
             "actor/0", cfg=cfg, hp=self.hp, seed=rl.seed,
-            devices=self.cluster.allocate("actor", 4))
+            devices=self.cluster.allocate("actor", min(4, n)))
         self.rollout = RolloutWorker(
             "rollout/0", cfg=cfg, max_new_tokens=rl.max_new_tokens,
             temperature=rl.temperature, seed=rl.seed,
-            devices=self.cluster.allocate("rollout", 4))
+            devices=self.cluster.allocate("rollout", min(4, n)))
         self.inference = InferenceWorker(
             "inference/0", cfg=cfg,
-            devices=self.cluster.allocate("inference", 2))
+            devices=self.cluster.allocate("inference", min(2, n)))
         self.reward = RewardWorker(
             "reward/0", prompt_len=rl.prompt_len, group_size=rl.group_size)
         return {"rollout": self.rollout, "inference": self.inference,

@@ -139,6 +139,16 @@ class CacheLayout:
         raise NotImplementedError
 
     # -- shared sampling tail ----------------------------------------------
+    # the params' mesh when it spans several devices (the fused sampler
+    # then runs under shard_map); set on the host before each jitted
+    # step and read while tracing, which input shardings key
+    _mesh = None
+
+    def _note_mesh(self, params) -> None:
+        mesh = getattr(jax.tree_util.tree_leaves(params)[0].sharding,
+                       "mesh", None)
+        self._mesh = mesh if mesh is not None and mesh.size > 1 else None
+
     def _sample_batch(self, logits, seeds, positions):
         """Per-request deterministic sampling: token at ``position`` of a
         request seeded ``seed`` is drawn from fold_in(PRNGKey(seed), pos)
@@ -150,7 +160,7 @@ class CacheLayout:
             return sample_tokens_fused(
                 keys, logits, temperature=self.temperature,
                 top_k=self.top_k, top_p=self.top_p,
-                vocab_size=self.cfg.vocab_size)
+                vocab_size=self.cfg.vocab_size, mesh=self._mesh)
         return jax.vmap(functools.partial(
             sample_token, temperature=self.temperature, top_k=self.top_k,
             top_p=self.top_p, vocab_size=self.cfg.vocab_size))(keys, logits)
@@ -308,6 +318,7 @@ class PagedKVLayout(CacheLayout):
 
     # -- host-facing API ----------------------------------------------------
     def step(self, params, tokens, positions, tables, seeds, active):
+        self._note_mesh(params)
         tok, lp, kc, vc = self._step_fn(
             params, self.cache.k, self.cache.v, jnp.asarray(tokens),
             jnp.asarray(positions), jnp.asarray(tables),
@@ -461,6 +472,7 @@ class StateCacheLayout(CacheLayout):
 
     # -- host-facing API ----------------------------------------------------
     def step(self, params, tokens, positions, tables, seeds, active):
+        self._note_mesh(params)
         tok, lp, self.cache = self._step_fn(
             params, self.cache, jnp.asarray(tokens),
             jnp.asarray(positions), jnp.asarray(seeds),

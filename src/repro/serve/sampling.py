@@ -8,10 +8,12 @@ well-defined whatever decoding strategy produced the trajectory.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro.models.layers import token_logprobs
 
@@ -85,6 +87,7 @@ def sample_tokens_fused(
     top_k: int = 0,
     top_p: float = 1.0,
     vocab_size: int = 0,
+    mesh=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Batched :func:`sample_token` through the fused Pallas kernel.
 
@@ -93,7 +96,20 @@ def sample_tokens_fused(
     per-row keys and fusing filter+argmax in the kernel reproduces the
     unfused path draw-for-draw; parity sweeps in test_kernels.py hold
     the two together.
+
+    ``mesh``: the multi-device mesh the caller's jitted step is
+    replicated over.  A Mosaic kernel cannot be partitioned
+    automatically, so there the sampler runs under ``shard_map``, every
+    device sampling the whole (replicated) batch.
     """
+    if mesh is not None:
+        rep = PartitionSpec()
+        return jax.shard_map(
+            functools.partial(sample_tokens_fused, temperature=temperature,
+                              top_k=top_k, top_p=top_p,
+                              vocab_size=vocab_size),
+            mesh=mesh, in_specs=(rep, rep), out_specs=(rep, rep),
+            check_vma=False)(keys, logits)
     from repro.kernels import ops as kops
 
     logits = logits.astype(jnp.float32)

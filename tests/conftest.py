@@ -7,68 +7,8 @@ can stand up a simulated multi-host cluster without touching XLA flags:
 
     REPRO_DRYRUN_HOSTS=4 REPRO_DRYRUN_DEVICES=8 python -m repro.launch.dryrun
 """
-import os
-import sys
-
 import jax
 import pytest
-
-# ---------------------------------------------------------------------------
-# Gate the optional `hypothesis` dependency: the pinned container does not
-# ship it, so property tests fall back to a deterministic mini-fuzzer with
-# the same decorator surface (given/settings/strategies.integers|floats|
-# sampled_from).  A real hypothesis install always wins.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import random
-    import types
-
-    class _Strategy:
-        def __init__(self, draw):
-            self.draw = draw  # draw(rnd) -> value
-
-    def _integers(lo, hi):
-        return _Strategy(lambda rnd: rnd.randint(lo, hi))
-
-    def _floats(lo, hi):
-        return _Strategy(lambda rnd: rnd.uniform(lo, hi))
-
-    def _sampled_from(seq):
-        vals = list(seq)
-        return _Strategy(lambda rnd: rnd.choice(vals))
-
-    def _given(**strats):
-        def deco(fn):
-            def wrapper(*args, **kwargs):
-                rnd = random.Random(0)
-                n = getattr(wrapper, "_max_examples", 10)
-                for _ in range(n):
-                    case = {k: s.draw(rnd) for k, s in strats.items()}
-                    fn(*args, **case, **kwargs)
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper._max_examples = 10
-            return wrapper
-        return deco
-
-    def _settings(max_examples=10, deadline=None, **_kw):
-        def deco(fn):
-            fn._max_examples = max_examples
-            return fn
-        return deco
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.floats = _floats
-    _st.sampled_from = _sampled_from
-    _hyp.strategies = _st
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
 
 # keep CPU tests deterministic and fast
 jax.config.update("jax_enable_x64", False)

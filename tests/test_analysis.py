@@ -454,6 +454,31 @@ def test_k106_gqa_head_mismatch():
     assert codes(fs) <= {"K106", "K104"}
 
 
+# (operand shape, block shape) of the four kernel layouts Mosaic refused
+# before they were reblocked
+_UNTILED = {
+    "paged_kv_head_slice": ((65, 16, 8, 64), (1, 16, 1, 64)),
+    "ssd_dt_row": ((2, 32, 4, 128), (1, 1, 1, 128)),
+    "ssm_update_x_row": ((8, 32, 64), (1, 1, 64)),
+    "sampling_one_row": ((8, 49155), (1, 49155)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_UNTILED))
+def test_k108_block_off_the_mosaic_tiling(case):
+    shape, block = _UNTILED[case]
+
+    def inv(blk):
+        return KernelInvocation(
+            kernel="toy", shape_name="t", grid=(1,),
+            operands=[BlockMap("a", shape, blk,
+                               lambda i: (0,) * len(shape))])
+
+    assert codes(check_invocation(inv(block))) == {"K108"}
+    # the same operand blocked whole is accepted
+    assert codes(check_invocation(inv(shape))) == set()
+
+
 def test_k107_uncovered_kernel_entry():
     fs = check_registry_coverage(
         [flash_invocation("t", B=2, H=28, S=4096, D=128, KV=4)])

@@ -64,6 +64,7 @@ def test_flash_attention_block_shape_property(bq, bk, s_mult):
     (1, 2, 16, 8, 64, 16),
     (2, 4, 32, 16, 128, 32),
     (1, 1, 64, 64, 256, 64),
+    (3, 2, 16, 8, 96, 32),  # batch > 1, three chunks carried
 ])
 def test_ssd_scan_sweep(B, H, P, N, L, chunk, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
@@ -96,6 +97,7 @@ def test_ssd_scan_sweep(B, H, P, N, L, chunk, dtype):
     (1, 2, 16, 8),
     (3, 4, 32, 16),
     (2, 24, 64, 128),  # mamba2-370m head geometry
+    (5, 3, 8, 16),
 ])
 def test_ssm_state_update_sweep(B, H, P, N, dtype):
     ks = jax.random.split(jax.random.PRNGKey(1), 6)
@@ -213,6 +215,7 @@ def _paged_inputs(key, B, H, KV, D, P, page, nb, dtype=jnp.float32):
     (3, 4, 2, 16, 8, 4),    # GQA groups of 2
     (2, 8, 8, 64, 16, 3),   # MHA
     (4, 6, 2, 32, 4, 5),    # 3-way GQA groups
+    (3, 24, 8, 64, 16, 2),  # granite-moe heads: 8 KV heads x 3
 ])
 def test_paged_attention_sweep(B, H, KV, D, page, nb, dtype):
     P = nb * B + 1
@@ -310,7 +313,8 @@ def _sampling_inputs(seed, B, V):
     return logits, gumbel, keys
 
 
-@pytest.mark.parametrize("B,V", [(1, 64), (4, 128), (3, 250)])
+@pytest.mark.parametrize("B,V", [(1, 64), (4, 128), (3, 250),
+                                 (9, 300)])  # pads to two 8-row blocks
 @pytest.mark.parametrize("temperature,top_k,top_p,vocab_size", [
     (0.0, 0, 1.0, 0),     # greedy
     (1.0, 0, 1.0, 0),     # plain categorical

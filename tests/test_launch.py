@@ -50,6 +50,21 @@ def test_train_launcher_smoke():
     assert "step 0" in out.stdout and "tok/s" in out.stdout
 
 
+def test_train_launcher_mesh_spans_all_devices():
+    """The launcher's mesh covers every device present (here four
+    virtual CPU devices), not a hard-coded pod shape."""
+    env = dict(_subprocess_env(),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.train", "--arch", "yi-9b",
+         "--smoke", "--steps", "2", "--batch", "4", "--seq", "32"],
+        capture_output=True, text=True, timeout=420, env=env,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "mesh={'data': 4, 'model': 1}" in out.stdout, out.stdout
+    assert "step 1" in out.stdout
+
+
 def test_resharding_between_specs_subprocess():
     """Reshard a pytree between two different layouts on an 8-device mesh
     and verify values survive (the weight-update barrier path)."""
@@ -59,7 +74,7 @@ def test_resharding_between_specs_subprocess():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.comm import reshard
-        from repro.launch.mesh import _make_mesh  # AxisType compat shim
+        from repro.launch.mesh import _make_mesh
         mesh = _make_mesh((2, 4), ("data", "model"))
         x = jnp.arange(64.0).reshape(8, 8)
         a = jax.device_put(x, NamedSharding(mesh, P("data", "model")))

@@ -6,6 +6,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
+from repro.core import Cluster
 from repro.models import init_model
 from repro.rl import (
     EnvConfig,
@@ -144,6 +145,27 @@ def test_grpo_runner_modes(mode):
     assert len(stats) == 2
     assert all(np.isfinite(s.mean_reward) for s in stats)
     assert runner.throughput() > 0
+
+
+def test_grpo_runner_on_one_device_cluster():
+    """A one-device cluster (one chip) hosts every worker: the fixed
+    construction-time slices are capped at the cluster, and each worker's
+    mesh is exactly the device its placement names."""
+    cfg = get_config("yi-9b").reduced().replace(
+        vocab_size=32, d_model=64, num_heads=4, num_kv_heads=2, head_dim=16,
+        d_ff=128)
+    rl = GRPOConfig(batch_size=8, group_size=4, iterations=2,
+                    max_new_tokens=4, mode="collocated", seed=0,
+                    profile_batches=(8,))
+    runner = GRPORunner(cfg, rl, TrainHParams(optimizer=AdamWConfig(lr=1e-3)),
+                        cluster=Cluster(num_nodes=1, devices_per_node=1))
+    stats = runner.run(verbose=False)
+    assert len(stats) == 2
+    assert runner.rollout.engine.weight_version > 0
+    for name, ids in runner.plan.placement.items():
+        assert ids == [0]
+        assert list(runner.workers[name].device_mesh.devices.flat) == [
+            jax.devices()[0]]
 
 
 def test_grpo_runner_learns_on_tiny_task():
