@@ -50,19 +50,24 @@ def timed_weight_sync(params: Any, dst_shardings: Any
                       ) -> Tuple[Any, float]:
     """Reshard + block, returning (new_tree, seconds) — the measured
     weight-update-barrier cost the scheduler charges between training and
-    generation stages."""
+    generation stages.  Traced, it is one ``weight-sync`` span."""
+    tr = _trace.active()
+    if tr is None:
+        return _synced(params, dst_shardings)
+    stats = transfer_stats(params)
+    with tr.span("weight-sync", "sync", bytes=stats["bytes"],
+                 arrays=int(stats["arrays"])):
+        out, dt = _synced(params, dst_shardings)
+    reg = _metrics.active()
+    if reg is not None:
+        reg.counter("sync/count").inc()
+        reg.counter("sync/bytes").inc(stats["bytes"])
+        reg.histogram("sync/seconds").observe(dt)
+    return out, dt
+
+
+def _synced(params: Any, dst_shardings: Any) -> Tuple[Any, float]:
     t0 = time.perf_counter()
     out = reshard(params, dst_shardings)
     jax.block_until_ready(out)
-    t1 = time.perf_counter()
-    tr = _trace.active()
-    if tr is not None:
-        stats = transfer_stats(params)
-        tr.add("weight-sync", "sync", t0, t1, bytes=stats["bytes"],
-               arrays=int(stats["arrays"]))
-        reg = _metrics.active()
-        if reg is not None:
-            reg.counter("sync/count").inc()
-            reg.counter("sync/bytes").inc(stats["bytes"])
-            reg.histogram("sync/seconds").observe(t1 - t0)
-    return out, t1 - t0
+    return out, time.perf_counter() - t0

@@ -270,13 +270,23 @@ class ExecutionFlowManager:
     def _apply(self, worker_name: str, chunk: Dict, idx: int) -> Dict:
         w = self.workers[worker_name]
         fn = self.task_fns[worker_name]
+        tr = _trace.active()
         try:
             if getattr(w, "offloaded", False):
                 w.onload()
             if self.heartbeat is not None:
                 self.heartbeat.beat(worker_name)
             t0 = time.perf_counter()
-            out = fn(w, chunk)
+            if tr is None:
+                out = fn(w, chunk)
+            else:
+                # the executor's task choke point: every worker invocation
+                # in every realization passes through here, so this one
+                # span is the whole busy timeline
+                with tr.span(worker_name, "task", worker=worker_name,
+                             chunk=idx,
+                             devices=list(getattr(w, "devices", ()))):
+                    out = fn(w, chunk)
             if self.heartbeat is not None:
                 self.heartbeat.beat(worker_name)
         except WorkerFailure as f:
@@ -293,13 +303,6 @@ class ExecutionFlowManager:
             raise f from e
         t1 = time.perf_counter()
         self._record(worker_name, t0, t1, idx)
-        tr = _trace.active()
-        if tr is not None:
-            # the executor's task choke point: every worker invocation in
-            # every realization passes through here, so this one span is
-            # the whole busy timeline (timestamps reused from _record)
-            tr.add(worker_name, "task", t0, t1, worker=worker_name,
-                   chunk=idx, devices=list(getattr(w, "devices", ())))
         return out
 
     def _run(self, sched, batch: Dict) -> Dict:

@@ -26,13 +26,36 @@ from __future__ import annotations
 import threading
 import time
 from dataclasses import dataclass
-from typing import Any, Dict, Iterable, List, Optional, Sequence
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
+import jax
+
+from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 
 # Cold keys leave the device first; anything unlisted (e.g. "params")
 # follows in registration order.
 OFFLOAD_KEY_ORDER = ("opt",)
+
+
+def _timed(fn, *args, **kw) -> Tuple[Any, float]:
+    t0 = time.perf_counter()
+    out = fn(*args, **kw)
+    return out, time.perf_counter() - t0
+
+
+def _onload_ready(w) -> Tuple[str, ...]:
+    """``w.onload()``, returning once the moved state is on the device."""
+    moved = w.onload()
+    if moved:
+        jax.block_until_ready([w.get_state(k) for k in moved])
+    return moved
+
+
+def _count_bytes(nbytes: int) -> None:
+    reg = _metrics.active()
+    if reg is not None:
+        reg.counter("switch/bytes").inc(nbytes)
 
 
 @dataclass
@@ -64,51 +87,60 @@ class ContextSwitcher:
 
     # ------------------------------------------------------------------
     def offload_worker(self, name: str) -> float:
-        """Per-key offload of one worker; returns measured seconds."""
+        """Per-key offload of one worker; returns measured seconds.
+        Traced, each key that leaves the device is an ``offload:<name>``
+        span and adds its bytes to the ``switch/bytes`` counter."""
         w = self.workers.get(name)
         if w is None or not hasattr(w, "offload"):
             return 0.0
-        state_keys = list(getattr(w, "_state", {}) or {})
-        keys = [k for k in OFFLOAD_KEY_ORDER if k in state_keys]
-        keys += [k for k in state_keys if k not in keys]
+        state = getattr(w, "_state", None) or {}
+        # resident keys only: an offloaded key's device slot holds None
+        keys = [k for k in OFFLOAD_KEY_ORDER if state.get(k) is not None]
+        keys += [k for k in state if k not in keys and state[k] is not None]
         total, moved_any = 0.0, False
         tr = _trace.active()
         for k in keys:
-            t0 = time.perf_counter()
-            moved = w.offload(keys=(k,))
-            t1 = time.perf_counter()
-            dt = t1 - t0
+            if tr is None:
+                moved, dt = _timed(w.offload, keys=(k,))
+            else:
+                nbytes = w.state_bytes((k,))
+                with tr.span(f"offload:{name}", "switch", worker=name,
+                             key=k, bytes=nbytes):
+                    moved, dt = _timed(w.offload, keys=(k,))
+                _count_bytes(nbytes)
             if moved:
                 moved_any = True
                 total += dt
                 with self._lock:
                     self.records.append(
                         SwitchRecord(name, "offload", k, dt))
-                if tr is not None:
-                    tr.add(f"offload:{name}", "switch", t0, t1,
-                           worker=name, key=k)
         if moved_any:
             self._feedback(name, "offload_time", total)
         return total
 
     def onload_worker(self, name: str) -> float:
-        """Restore one worker's host state; returns measured seconds."""
+        """Restore one worker's host state; returns measured seconds, up
+        to the moment the moved state is ready on the device (a
+        ``device_put`` returns once the copy is enqueued).  Traced, it
+        is one ``onload:<name>`` span and adds the bytes moved to the
+        ``switch/bytes`` counter."""
         w = self.workers.get(name)
         if w is None or not hasattr(w, "onload"):
             return 0.0
-        t0 = time.perf_counter()
-        moved = w.onload()
-        t1 = time.perf_counter()
-        dt = t1 - t0
+        tr = _trace.active()
+        if tr is None:
+            moved, dt = _timed(_onload_ready, w)
+        else:
+            with tr.span(f"onload:{name}", "switch", worker=name) as args:
+                moved, dt = _timed(_onload_ready, w)
+                nbytes = w.state_bytes(moved) if moved else 0
+                args.update(key="+".join(moved or ()), bytes=nbytes)
+            _count_bytes(nbytes)
         if not moved:
             return 0.0
         with self._lock:
             self.records.append(
                 SwitchRecord(name, "onload", "+".join(moved), dt))
-        tr = _trace.active()
-        if tr is not None:
-            tr.add(f"onload:{name}", "switch", t0, t1,
-                   worker=name, key="+".join(moved))
         self._feedback(name, "onload_time", dt)
         return dt
 

@@ -153,9 +153,11 @@ class Worker:
             self._host_state.pop(key, None)
             self._offloaded.discard(key)
 
-    def state_bytes(self) -> int:
+    def state_bytes(self, keys: Optional[Sequence[str]] = None) -> int:
+        """Bytes of the device-resident state (of ``keys``, or all)."""
         total = 0
-        for tree in self._state.values():
+        ks = self._state if keys is None else keys
+        for tree in (self._state.get(k) for k in ks):
             for l in jax.tree_util.tree_leaves(tree):
                 if hasattr(l, "nbytes"):
                     total += int(l.nbytes)

@@ -13,7 +13,11 @@ Design constraints:
 
   * **zero dependencies** — stdlib only, importable from every layer
     (``core.channel`` and ``comm.resharding`` both instrument; obs must
-    never import back into them);
+    never import back into them).  A live span (:meth:`Tracer.span`)
+    also opens a ``jax.profiler.TraceAnnotation`` named
+    ``repro:<span name>`` while JAX is loaded and a profiler session
+    records, so the program's spans land on the profile's host plane,
+    on the device trace's clock;
   * **monotonic clocks** — spans carry absolute ``time.perf_counter``
     stamps; export normalizes to the tracer's epoch.  The clock is
     injectable so tests replay fixed timelines and assert deterministic
@@ -26,11 +30,28 @@ Design constraints:
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
+from typing import (Any, Callable, ContextManager, Dict, Iterator, List,
+                    Optional, Tuple)
+
+
+ANNOTATION_PREFIX = "repro:"
+_NO_ANNOTATION = nullcontext()
+
+
+def _annotation(name: str) -> ContextManager:
+    """A ``jax.profiler.TraceAnnotation`` for span ``name`` while JAX is
+    loaded and a profiler session records, else a no-op: this module
+    never imports JAX itself, and an annotation made outside a session
+    would record nothing."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None or not profiler.TraceAnnotation.is_enabled():
+        return _NO_ANNOTATION
+    return profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
 
 
 @dataclass
@@ -148,29 +169,16 @@ class Tracer:
             self._counters.append(CounterSample(
                 name, self.clock() if t is None else t, float(value)))
 
-    @contextmanager
     def span(self, name: str, cat: str = "span",
-             lane: Optional[str] = None, **args: Any) -> Iterator[None]:
-        t0 = self.clock()
-        try:
-            yield
-        finally:
-            self.add(name, cat, t0, self.clock(), lane=lane, **args)
-
-    def trace(self, name: Optional[str] = None, cat: str = "task",
-              **args: Any) -> Callable:
-        """Decorator form of :meth:`span`."""
-        def deco(fn: Callable) -> Callable:
-            label = name or getattr(fn, "__name__", "fn")
-
-            def wrapped(*a: Any, **kw: Any) -> Any:
-                with self.span(label, cat, **args):
-                    return fn(*a, **kw)
-
-            wrapped.__name__ = getattr(fn, "__name__", label)
-            wrapped.__doc__ = fn.__doc__
-            return wrapped
-        return deco
+             lane: Optional[str] = None, **args: Any) -> "_LiveSpan":
+        """Time the body of a ``with`` as one span.  ``as`` gives the
+        span's args: the body may add what it learns while running
+        (bytes moved, tokens advanced), recorded when the span ends.
+        While a profiler session records, the body also runs inside a
+        ``jax.profiler.TraceAnnotation`` named ``repro:<name>``, so the
+        profile shows the span on its host plane, on the device trace's
+        clock."""
+        return _LiveSpan(self, name, cat, lane, args)
 
     # ------------------------------------------------------------------
     # access
@@ -250,6 +258,30 @@ class Tracer:
             json.dump(self.to_chrome(), f, sort_keys=True,
                       separators=(",", ":"))
             f.write("\n")
+
+
+class _LiveSpan:
+    """The context manager of :meth:`Tracer.span` (a class, not a
+    generator: it sits on the executor's and the engine's hot paths)."""
+
+    __slots__ = ("tracer", "name", "cat", "lane", "args", "t0", "ann")
+
+    def __init__(self, tracer: Tracer, name: str, cat: str,
+                 lane: Optional[str], args: Dict[str, Any]):
+        self.tracer, self.name, self.cat = tracer, name, cat
+        self.lane, self.args = lane, args
+
+    def __enter__(self) -> Dict[str, Any]:
+        self.ann = _annotation(self.name)
+        self.ann.__enter__()
+        self.t0 = self.tracer.clock()
+        return self.args
+
+    def __exit__(self, *exc: Any) -> None:
+        tr = self.tracer
+        tr.add(self.name, self.cat, self.t0, tr.clock(), lane=self.lane,
+               **self.args)
+        self.ann.__exit__(*exc)
 
 
 # ---------------------------------------------------------------------------

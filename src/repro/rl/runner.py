@@ -256,26 +256,31 @@ class WorkflowRunner:
 
     # ------------------------------------------------------------------
     def run_iteration(self, it: int):
+        """One iteration: weight sync, batch, the plan's execution.
+        Traced, it is one ``iteration`` span, and every event recorded
+        meanwhile carries ``iteration=it`` in its args."""
         t0 = time.perf_counter()
-        tr = _trace.active()
-        if tr is not None:
-            tr.set_context(iteration=it)
         if self.fault_injector is not None:
             self.fault_injector.set_iteration(it)
-        try:
-            self._sync_weights()
-            batch = self.make_batch()
-            out = self.controller.execute(
-                self.plan, self.workers, self.task_fns, batch,
-                cycle_specs=self.cycle_specs())
-            out = self.post_execute(out)
-        finally:
-            wall = time.perf_counter() - t0
-            if tr is not None:
-                tr.add(f"iteration-{it}", "iteration", t0,
-                       time.perf_counter())
+        tr = _trace.active()
+        if tr is None:
+            out = self._iteration()
+        else:
+            tr.set_context(iteration=it)
+            try:
+                with tr.span("iteration", "iteration"):
+                    out = self._iteration()
+            finally:
                 tr.set_context(iteration=None)
-        return self._record_stats(it, wall, out)
+        return self._record_stats(it, time.perf_counter() - t0, out)
+
+    def _iteration(self):
+        self._sync_weights()
+        batch = self.make_batch()
+        out = self.controller.execute(
+            self.plan, self.workers, self.task_fns, batch,
+            cycle_specs=self.cycle_specs())
+        return self.post_execute(out)
 
     # ------------------------------------------------------------------
     # periodic trainer checkpointing + resume (train.checkpoint)

@@ -22,9 +22,8 @@ mode is chosen (one-forward-pass trick, §5.3).
 from __future__ import annotations
 
 import threading
-import time
 from collections import deque
-from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -372,10 +371,22 @@ class PagedEngine:
         in-flight writer of their shared prefix sit the step out, the
         remaining prompt work is chunk-prefilled under the
         ``prefill_chunk`` token budget, and everyone at the sampling
-        frontier decodes one token in the fixed-shape batch."""
+        frontier decodes one token in the fixed-shape batch.
+
+        Traced, the step is one ``engine-step`` span holding an
+        ``engine-prefill`` span per prefill chunk and an
+        ``engine-decode`` span from the batch step's dispatch until its
+        sampled tokens reach the host: the rest of the step is host
+        bookkeeping."""
         tr = _trace.active()
+        if tr is None:
+            return self._step(None, None)
+        with tr.span("engine-step", "engine") as span_args:
+            return self._step(tr, span_args)
+
+    def _step(self, tr: Optional[_trace.Tracer],
+              span_args: Optional[Dict[str, Any]]) -> int:
         reg = _metrics.active()
-        t_step = time.perf_counter() if tr is not None else 0.0
         self._apply_pending()  # before the check: update_weights() alone
         # is a valid way to deliver the initial weights
         assert self.params is not None, "engine weights not initialized"
@@ -402,8 +413,7 @@ class PagedEngine:
                         self.prefix_cache.num_pages)
         if not reqs:
             if tr is not None:
-                tr.add("engine-step", "engine", t_step, time.perf_counter(),
-                       advanced=0, prefill=0, decode=0, chunked=0)
+                span_args.update(advanced=0, prefill=0, decode=0, chunked=0)
             return 0
         budget = self.prefill_chunk
         chunked_tokens = 0
@@ -424,7 +434,12 @@ class PagedEngine:
                 need = r.total_len - 1 - r.num_cached
                 grant = min(need, budget)
                 if grant > 0:
-                    self._prefill_chunk_step(r, grant)
+                    if tr is None:
+                        self._prefill_chunk_step(r, grant)
+                    else:
+                        with tr.span("engine-prefill", "engine", rid=r.rid,
+                                     tokens=grant):
+                            self._prefill_chunk_step(r, grant)
                     budget -= grant
                     chunked_tokens += grant
                     # a chunk may complete up to a watermark another
@@ -459,9 +474,14 @@ class PagedEngine:
                                                      self.max_blocks)
                 seeds[r.slot] = r.seed
                 active[r.slot] = True
-            tok, lp = self.layout.step(self.params, tokens, positions,
-                                       tables, seeds, active)
-            tok_np, lp_np = np.asarray(tok), np.asarray(lp)
+            if tr is None:
+                tok_np, lp_np = self._decode(tokens, positions, tables,
+                                             seeds, active)
+            else:
+                with tr.span("engine-decode", "engine",
+                             batch=len(decode_reqs)):
+                    tok_np, lp_np = self._decode(tokens, positions, tables,
+                                                 seeds, active)
             for r in decode_reqs:
                 pos = r.num_cached
                 r.num_cached += 1
@@ -498,11 +518,17 @@ class PagedEngine:
             # was a prefill (teacher-forced) step, the rest decoded
             prefill = sum(1 for r in decode_reqs
                           if r.num_cached < r.prompt_len)
-            tr.add("engine-step", "engine", t_step, time.perf_counter(),
-                   advanced=advanced, prefill=prefill,
-                   decode=len(decode_reqs) - prefill,
-                   chunked=chunked_tokens)
+            span_args.update(advanced=advanced, prefill=prefill,
+                             decode=len(decode_reqs) - prefill,
+                             chunked=chunked_tokens)
         return advanced
+
+    def _decode(self, tokens, positions, tables, seeds, active):
+        """One batch step of every slot; returns the sampled tokens and
+        their logprobs on the host."""
+        tok, lp = self.layout.step(self.params, tokens, positions, tables,
+                                   seeds, active)
+        return np.asarray(tok), np.asarray(lp)
 
     # ------------------------------------------------------------------
     # prefix sharing + chunked prefill plumbing

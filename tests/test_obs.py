@@ -80,21 +80,36 @@ def test_spans_nest_properly():
 
 
 def test_decorator_and_context_attributes():
+    """set_context stamps live spans; args the body adds are recorded."""
     clk = FakeClock()
     tr = Tracer(clock=clk)
 
-    @tr.trace("work", cat="task")
     def work():
-        clk.advance(1.0)
-        return 7
+        with tr.span("work", "task", chunk=0) as args:
+            clk.advance(1.0)
+            args["bytes"] = 7
 
     tr.set_context(iteration=3)
-    assert work() == 7
+    work()
     tr.set_context(iteration=None)
-    assert work() == 7
+    work()
     a, b = tr.spans("task")
-    assert a.args["iteration"] == 3
-    assert "iteration" not in b.args
+    assert a.args == {"iteration": 3, "chunk": 0, "bytes": 7}
+    assert b.args == {"chunk": 0, "bytes": 7}
+    assert a.dur == pytest.approx(1.0)
+
+
+def test_span_is_recorded_when_its_body_raises():
+    clk = FakeClock()
+    tr = Tracer(clock=clk)
+    with pytest.raises(ValueError):
+        with tr.span("boom", "task") as args:
+            clk.advance(0.5)
+            args["seen"] = True
+            raise ValueError("x")
+    (s,) = tr.spans("task")
+    assert s.name == "boom" and s.dur == pytest.approx(0.5)
+    assert s.args == {"seen": True}
 
 
 def test_thread_lanes_and_names():
@@ -149,6 +164,70 @@ def test_tracing_default_off_and_scoped():
     with tracing() as tr:
         assert trace_mod.active() is tr
     assert trace_mod.active() is None
+
+
+# ---------------------------------------------------------------------------
+# live spans on the profiler's clock
+# ---------------------------------------------------------------------------
+def _profiled_program_events(log_dir, fn):
+    """(name, start_ns, end_ns) of the ``repro:`` events on the host plane
+    of a ``jax.profiler`` trace of ``fn()``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    host = next(p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU")
+    return sorted((e.name, int(e.start_ns), int(e.end_ns))
+                  for line in host.lines for e in line.events
+                  if e.name.startswith(trace_mod.ANNOTATION_PREFIX))
+
+
+def _jitted_task():
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: x * 2.0 + 1.0)
+    x = jnp.ones((32, 32), jnp.float32)
+    f(x).block_until_ready()  # compiled outside the trace
+
+    def task(w, chunk):
+        f(x).block_until_ready()
+        return chunk
+    return task
+
+
+def _run_one_task(task):
+    workers = {"a": _DevWorker([0])}
+    ExecutionFlowManager(workers, {"a": task}).run(
+        Leaf("a", 1, 4), {"x": np.zeros((4, 2), np.float32)})
+
+
+def test_live_spans_appear_on_the_profiler_host_plane(tmp_path):
+    task = _jitted_task()
+
+    def run():
+        with tracing() as tr:
+            with tr.span("outer", "phase"):
+                _run_one_task(task)
+
+    events = _profiled_program_events(tmp_path, run)
+    assert [n for n, _, _ in events] == ["repro:a", "repro:outer"]
+    (_, a0, a1), (_, o0, o1) = events
+    # the executor's task span nests inside the span around it
+    assert o0 <= a0 < a1 <= o1
+
+
+def test_no_annotation_while_tracing_is_disarmed(tmp_path):
+    task = _jitted_task()
+    assert _profiled_program_events(tmp_path, lambda: _run_one_task(task)) == []
 
 
 # ---------------------------------------------------------------------------
@@ -273,6 +352,91 @@ def test_temporal_shared_device_spans_sequential():
             sched, {"x": np.zeros((4, 2), np.float32)})
     ivs = [(s.t0, s.t1) for s in tr.spans("task")]
     assert len(ivs) == 2 and not _overlaps(ivs)
+
+
+# ---------------------------------------------------------------------------
+# context switch and iteration spans
+# ---------------------------------------------------------------------------
+def _state_worker(name):
+    import jax.numpy as jnp
+
+    from repro.core.worker import Worker
+
+    w = Worker(name, devices=(0,))
+    w.register_state("params", {"w": jnp.arange(8.0)})  # 32 bytes
+    w.register_state("opt", {"m": jnp.zeros(4)})         # 16 bytes
+    return w
+
+
+def test_onload_span_ends_after_the_transfer_is_ready(monkeypatch):
+    from repro.core import switching
+
+    w = _state_worker("ready/0")
+    sw = switching.ContextSwitcher({"r": w})
+    real, ready = switching.jax.block_until_ready, []
+
+    def block(trees):
+        out = real(trees)
+        ready.append((time.perf_counter(), trees))
+        return out
+
+    monkeypatch.setattr(switching.jax, "block_until_ready", block)
+    sw.offload_worker("r")
+    assert sw.onload_worker("r") > 0.0  # untraced: blocks all the same
+    assert len(ready) == 1
+    sw.offload_worker("r")
+    with tracing() as tr:
+        sw.onload_worker("r")
+    (span,) = tr.spans("switch")
+    t_ready, trees = ready[-1]
+    assert span.name == "onload:r" and span.t1 >= t_ready
+    # the block waited on the very trees now in the worker's state
+    assert {id(t) for t in trees} == {id(w.get_state(k))
+                                      for k in ("opt", "params")}
+    assert sorted(span.args["key"].split("+")) == ["opt", "params"]
+    assert span.args["bytes"] == 48
+    w.shutdown()
+
+
+def test_switch_bytes_counted_per_key():
+    from repro.core.switching import ContextSwitcher
+
+    w = _state_worker("bytes/0")
+    sw = ContextSwitcher({"b": w})
+    with tracing() as tr:
+        sw.offload_worker("b")
+        sw.offload_worker("b")  # nothing resident: no span, no bytes
+        sw.onload_worker("b")
+    spans = tr.spans("switch")
+    assert [(s.name, s.args["bytes"]) for s in spans] == [
+        ("offload:b", 16), ("offload:b", 32), ("onload:b", 48)]
+    assert [s.args["key"] for s in spans[:2]] == ["opt", "params"]
+    assert default_registry().snapshot()["switch/bytes"]["value"] == 96
+    w.shutdown()
+
+
+def test_iteration_span_is_named_iteration_with_its_number():
+    from repro.rl.runner import WorkflowRunner
+
+    class Runner(WorkflowRunner):
+        def __init__(self):
+            self.fault_injector = None
+
+        def _iteration(self):
+            with trace_mod.active().span("work", "task"):
+                return "out"
+
+        def _record_stats(self, it, wall, out):
+            return it, out
+
+    with tracing() as tr:
+        assert Runner().run_iteration(4) == (4, "out")
+        assert Runner().run_iteration(5) == (5, "out")
+    its = tr.spans("iteration")
+    assert [(s.name, s.args) for s in its] == [
+        ("iteration", {"iteration": 4}), ("iteration", {"iteration": 5})]
+    assert [s.args for s in tr.spans("task")] == [{"iteration": 4},
+                                                 {"iteration": 5}]
 
 
 # ---------------------------------------------------------------------------

@@ -596,6 +596,32 @@ def test_serve_metrics_surface_under_tracing(cfg, params):
         default_registry().clear()
 
 
+def test_engine_step_span_holds_prefill_and_decode_spans(cfg, params):
+    """Traced, each engine step is one span; its chunked prefills and
+    its batch decode are child spans inside it, and the step's args say
+    what it advanced."""
+    from repro.obs import tracing
+
+    ds = PromptDataset(3, prompt_len=6, seed=0)
+    prompts = np.asarray(ds.next_batch()["prompt_tokens"])
+    eng = PagedEngine(cfg, max_batch=2, page_size=4, max_new_tokens=3,
+                      temperature=0.0, prefill_chunk=4,
+                      prefix_sharing=False)
+    with tracing() as tr:
+        eng.generate(params, prompts, key=jax.random.PRNGKey(0))
+    spans = tr.spans("engine")
+    steps = [s for s in spans if s.name == "engine-step"]
+    kids = [s for s in spans if s.name in ("engine-prefill", "engine-decode")]
+    assert len(steps) == eng.decode_steps
+    assert {s.name for s in kids} == {"engine-prefill", "engine-decode"}
+    for k in kids:
+        assert sum(s.t0 <= k.t0 and k.t1 <= s.t1 for s in steps) == 1, k
+    assert sum(s.name == "engine-decode" for s in kids) == sum(
+        s.args["decode"] + s.args["prefill"] > 0 for s in steps)
+    assert sum(s.args["chunked"] for s in steps) == sum(
+        s.args["tokens"] for s in kids if s.name == "engine-prefill")
+
+
 # ---------------------------------------------------------------------------
 # profiler: measured tail factor
 # ---------------------------------------------------------------------------
