@@ -4,10 +4,11 @@ The continuous-batching engine (:class:`repro.serve.engine.PagedEngine`)
 is host-side scheduling — admission, chunk budgets, preemption, weight
 sync — over a device cache whose *shape* depends on the architecture:
 
-* :class:`PagedKVLayout` — the classic vLLM layout: a (L, P, page, KV,
-  hd) page pool addressed through per-request block tables.  Pages grow
-  with every decoded token, preemption recomputes, and the radix prefix
-  trie can share full pages and copy-on-write partial ones.
+* :class:`PagedKVLayout` — the classic vLLM layout: a lane-dense
+  (L, P, page, KV * hd) page pool addressed through per-request block
+  tables.  Pages grow with every decoded token, preemption recomputes,
+  and the radix prefix trie can share full pages and copy-on-write
+  partial ones.
 * :class:`MoEPagedKVLayout` — same KV pool; the FFN half of each layer
   routes through the exact top-k expert combine (optionally the grouped
   per-expert decode GEMM kernel, ``kernels.ops.moe_decode``).
@@ -169,14 +170,15 @@ class CacheLayout:
 # ===========================================================================
 # Paged KV (dense attention stacks) — the original layout, extracted
 # ===========================================================================
-def _paged_sdpa(q, k_pages, v_pages, block_tables, context_lens):
-    """Pure-JAX paged attention (gather through the block table + sdpa);
-    the XLA analogue of kernels/paged_attention.py, exact same math."""
-    B = q.shape[0]
-    _, page, KV, hd = k_pages.shape
-    nb = block_tables.shape[1]
-    k = k_pages[block_tables].reshape(B, nb * page, KV, hd)
-    v = v_pages[block_tables].reshape(B, nb * page, KV, hd)
+def _paged_sdpa(q, k_pages, v_pages, li, block_tables, context_lens,
+                kv_heads):
+    """Pure-JAX paged attention over layer ``li`` of the lane-dense pool
+    (gather through the block table + sdpa); the XLA analogue of
+    kernels/paged_attention.py, exact same math."""
+    B, nb = block_tables.shape
+    page = k_pages.shape[2]
+    k = k_pages[li, block_tables].reshape(B, nb * page, kv_heads, -1)
+    v = v_pages[li, block_tables].reshape(B, nb * page, kv_heads, -1)
     pos = jnp.arange(nb * page)
     mask = jnp.where(pos[None, :] < context_lens[:, None], 0.0,
                      NEG_INF)[:, None, None, :]  # (B, 1, 1, S)
@@ -220,8 +222,15 @@ class PagedKVLayout(CacheLayout):
                    block_tables, seeds):
         """One token for every slot.  All shapes fixed by construction:
         tokens/positions/seeds (max_batch,), block_tables
-        (max_batch, max_blocks), cache (L, P, page, KV, hd)."""
+        (max_batch, max_blocks), cache (L, P, page, KV * hd).
+
+        The pool rides the layer scan as carry, written and read by layer
+        index: scanned as ``xs``/``ys`` instead, each layer's pool would be
+        sliced out of the donated input and updated into a separate
+        stacked output, a copy of the whole pool per step."""
         cfg = self.cfg
+        B = tokens.shape[0]
+        KV = cfg.num_kv_heads
         x = embed(params["embed"], tokens[:, None])  # (B, 1, d)
         posb = positions[:, None]
         page = self.page_size
@@ -231,29 +240,37 @@ class PagedKVLayout(CacheLayout):
         ctx = positions + 1  # valid tokens after this step's write
 
         def layer_body(carry, xs):
-            x = carry
-            lp, kl, vl = xs  # kl/vl: (P, page, KV, hd)
+            x, k_pages, v_pages = carry
+            lp, li = xs
             h = rmsnorm(lp["ln1"], x, cfg.norm_eps)
             q, k, v = qkv_project(lp["attn"], cfg, h)  # (B, 1, H|KV, hd)
             q = apply_rope(q, posb, cfg.rope_theta)
             k = apply_rope(k, posb, cfg.rope_theta)
             # scatter this step's K/V into each request's current page
             # (inactive slots target the trash page)
-            kl = kl.at[page_idx, offset].set(k[:, 0].astype(kl.dtype))
-            vl = vl.at[page_idx, offset].set(v[:, 0].astype(vl.dtype))
+            k_pages = k_pages.at[li, page_idx, offset].set(
+                k.reshape(B, -1).astype(k_pages.dtype))
+            v_pages = v_pages.at[li, page_idx, offset].set(
+                v.reshape(B, -1).astype(v_pages.dtype))
             if self.use_kernel:
                 from repro.kernels import ops as kops
 
+                # the kernel takes one layer's (P, page, KV, hd) pool:
+                # this slices a copy of the layer out of the stack
+                kl = k_pages[li].reshape(*k_pages.shape[1:3], KV, -1)
+                vl = v_pages[li].reshape(*v_pages.shape[1:3], KV, -1)
                 out = kops.paged_attention(
                     q[:, 0], kl, vl, block_tables, ctx)[:, None]
             else:
-                out = _paged_sdpa(q, kl, vl, block_tables, ctx)
+                out = _paged_sdpa(q, k_pages, v_pages, li, block_tables,
+                                  ctx, KV)
             x = x + jnp.einsum("bshk,hkd->bsd", out, lp["attn"]["wo"])
             x = x + self._ffn(lp, rmsnorm(lp["ln2"], x, cfg.norm_eps))
-            return x, (kl, vl)
+            return (x, k_pages, v_pages), None
 
-        x, (k_pages, v_pages) = jax.lax.scan(
-            layer_body, x, (params["layers"], k_pages, v_pages))
+        (x, k_pages, v_pages), _ = jax.lax.scan(
+            layer_body, (x, k_pages, v_pages),
+            (params["layers"], jnp.arange(cfg.num_layers)))
         x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
         logits = unembed(params["embed"], x)[:, 0]  # (B, V)
         tok, lp = self._sample_batch(logits, seeds, positions)
@@ -268,6 +285,7 @@ class PagedKVLayout(CacheLayout):
         tokens/positions (C,), block_table (max_blocks,), n_valid ()."""
         cfg = self.cfg
         C = tokens.shape[0]
+        KV = cfg.num_kv_heads
         page = self.page_size
         S = self.max_blocks * page
         valid = jnp.arange(C) < n_valid
@@ -285,23 +303,26 @@ class PagedKVLayout(CacheLayout):
                          NEG_INF)[None, None]  # (1, 1, C, S)
 
         def layer_body(carry, xs):
-            x = carry
-            lp, kl, vl = xs  # kl/vl: (P, page, KV, hd)
+            x, k_pages, v_pages = carry
+            lp, li = xs
             h = rmsnorm(lp["ln1"], x, cfg.norm_eps)
             q, k, v = qkv_project(lp["attn"], cfg, h)  # (1, C, H|KV, hd)
             q = apply_rope(q, posb, cfg.rope_theta)
             k = apply_rope(k, posb, cfg.rope_theta)
-            kl = kl.at[page_idx, offset].set(k[0].astype(kl.dtype))
-            vl = vl.at[page_idx, offset].set(v[0].astype(vl.dtype))
-            kc = kl[block_table].reshape(1, S, *kl.shape[2:])
-            vc = vl[block_table].reshape(1, S, *vl.shape[2:])
+            k_pages = k_pages.at[li, page_idx, offset].set(
+                k.reshape(C, -1).astype(k_pages.dtype))
+            v_pages = v_pages.at[li, page_idx, offset].set(
+                v.reshape(C, -1).astype(v_pages.dtype))
+            kc = k_pages[li, block_table].reshape(1, S, KV, -1)
+            vc = v_pages[li, block_table].reshape(1, S, KV, -1)
             out = sdpa(q, kc, vc, mask)  # (1, C, H, hd)
             x = x + jnp.einsum("bshk,hkd->bsd", out, lp["attn"]["wo"])
             x = x + self._ffn(lp, rmsnorm(lp["ln2"], x, cfg.norm_eps))
-            return x, (kl, vl)
+            return (x, k_pages, v_pages), None
 
-        _, (k_pages, v_pages) = jax.lax.scan(
-            layer_body, x, (params["layers"], k_pages, v_pages))
+        (_, k_pages, v_pages), _ = jax.lax.scan(
+            layer_body, (x, k_pages, v_pages),
+            (params["layers"], jnp.arange(cfg.num_layers)))
         return k_pages, v_pages
 
     @staticmethod

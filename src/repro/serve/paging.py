@@ -29,7 +29,7 @@ Three layers live here:
   shared prefix another request is still prefilling.
 * :class:`PrefixCache` — the radix trie over token-id page blocks.
 * :class:`PagedKVCache` — the device-side page pool, one K and one V
-  array of shape ``(layers, num_pages, page_size, kv_heads, head_dim)``.
+  array of shape ``(layers, num_pages, page_size, kv_heads * head_dim)``.
   Page 0 is reserved as a *trash page*: inactive decode slots point their
   block tables at it so the fixed-shape jitted step can scatter
   unconditionally without corrupting live requests.
@@ -406,7 +406,13 @@ class PrefixCache:
 class PagedKVCache(NamedTuple):
     """Device-side page pool shared by every request on the engine.
 
-    k/v: (num_layers, num_pages, page_size, kv_heads, head_dim)
+    k/v: (num_layers, num_pages, page_size, kv_heads * head_dim)
+
+    Lane-dense: a row's heads are flattened into the minor axis.  With
+    the heads as a separate axis (8 KV heads of 64 fill half of a
+    128-lane tile) the TPU stores the pool with the page axis minor-most,
+    and every per-layer scatter or gather transposes the layer's pool
+    through a copy.
     """
 
     k: jax.Array
@@ -424,7 +430,7 @@ class PagedKVCache(NamedTuple):
 def init_paged_cache(num_layers: int, num_pages: int, page_size: int,
                      kv_heads: int, head_dim: int,
                      dtype=jnp.float32) -> PagedKVCache:
-    shape = (num_layers, num_pages, page_size, kv_heads, head_dim)
+    shape = (num_layers, num_pages, page_size, kv_heads * head_dim)
     return PagedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 

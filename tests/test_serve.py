@@ -574,6 +574,26 @@ def test_chunked_prefill_parity_and_deferral_accounting(cfg, params):
     assert big_eng.scheduler.stats.chunk_deferred_tokens == 0
 
 
+@pytest.mark.parametrize("chunk", [4, 32])
+def test_chunked_prefill_matches_decode_only_prefill(cfg, params, chunk):
+    """Prompts written into the page pool by prefill chunks, then decoded,
+    sample the tokens and logprobs of prompts fed one position per decode
+    step (``prefill_chunk=0``): both paths write and read the same rows."""
+    ds = PromptDataset(3, prompt_len=13, seed=4)
+    prompts = np.asarray(ds.next_batch()["prompt_tokens"])
+
+    def run(c):
+        eng = PagedEngine(cfg, max_batch=2, page_size=4, max_new_tokens=6,
+                          temperature=1.0, prefill_chunk=c)
+        return eng.generate(params, prompts, key=jax.random.PRNGKey(3))
+
+    chunked, decode_only = run(chunk), run(0)
+    np.testing.assert_array_equal(np.asarray(chunked.tokens),
+                                  np.asarray(decode_only.tokens))
+    np.testing.assert_allclose(np.asarray(chunked.logprobs),
+                               np.asarray(decode_only.logprobs), atol=1e-5)
+
+
 def test_serve_metrics_surface_under_tracing(cfg, params):
     """The serve-tier counters/gauges only record while tracing is
     armed, and land in the default registry under serve/ and engine/."""

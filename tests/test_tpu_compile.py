@@ -7,12 +7,16 @@ too much VMEM, primitives Mosaic cannot lower), which the interpret-mode
 tests in test_kernels.py cannot see.  Widths are granite-moe-3b-a800m's
 (d_model 1536, 24 heads / 8 KV heads of 64, 40 experts top-8 of 512,
 vocab 49155 padded to 51200) and mamba2-370m's (32 SSD heads of 64,
-state 128, chunk 128).
+state 128, chunk 128).  The paged engine's decode step and prefill chunk
+are compiled the same way, to read what the TPU compiler makes of the KV
+page pool: buffer layouts and temporaries.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -124,3 +128,63 @@ def test_fused_sampler_compiles_replicated_over_four_chips(topo,
     compiled = jax.jit(lambda k, lg: sample_tokens_fused(
         k, lg, vocab_size=49155, mesh=mesh)).lower(keys, logits).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _paged_engine_programs(one_chip, num_pages):
+    """The paged engine's decode step and prefill chunk, compiled for a
+    described v5e at granite-moe-3b-a800m widths (2 layers) over a pool of
+    ``num_pages`` pages; block tables stay 8 pages wide."""
+    from repro.configs import get_config
+    from repro.models import init_model
+    from repro.serve.engine import PagedEngine
+
+    cfg = get_config("granite-moe-3b-a800m").replace(num_layers=2)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_model(jax.random.PRNGKey(0), cfg)))
+    eng = PagedEngine(cfg, max_batch=8, max_seq_len=128,
+                      num_pages=num_pages, max_new_tokens=32,
+                      use_sampling_kernel=False)
+    lay, B, C = eng.layout, eng.max_batch, eng.prefill_chunk
+    pools = on_chip((lay.cache.k, lay.cache.v))
+    step = lay._step_fn.lower(
+        params, *pools, i32(B), i32(B), i32(B, eng.max_blocks),
+        i32(B)).compile()
+    prefill = lay._prefill_fn.lower(
+        params, *pools, i32(C), i32(C), i32(eng.max_blocks),
+        i32()).compile()
+    return {"step": step, "prefill": prefill}, lay.cache.k.shape
+
+
+@pytest.fixture(scope="module")
+def paged_programs(one_chip):
+    return {n: _paged_engine_programs(one_chip, n) for n in (65, 1025)}
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_paged_engine_updates_kv_pool_in_place(program, paged_programs):
+    """The page pool is carried through the layer scan and written by
+    scatter in place: no copy, slice or slice update of a layer's or the
+    whole pool is compiled, and the temporaries do not grow with the
+    pool by as much as one layer's K pool."""
+    (small, _), (big, shape) = (paged_programs[65], paged_programs[1025])
+    pages = shape[1]
+    moved = []
+    for line in big[program].as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                     r"(copy|dynamic-slice|dynamic-update-slice)\(", line)
+        if m and pages in [int(d) for d in m.group(1).split(",") if d]:
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+    grown = (big[program].memory_analysis().temp_size_in_bytes
+             - small[program].memory_analysis().temp_size_in_bytes)
+    layer_growth = (pages - 65) * shape[2] * shape[3] * 4  # float32
+    assert grown < layer_growth, (grown, layer_growth)
